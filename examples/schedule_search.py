@@ -33,6 +33,7 @@ import argparse
 import repro.rules as R
 import repro.search as S
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.driver import ACQUISITIONS
 from repro.core.stepdag import StepCosts, train_step_dag, \
@@ -132,6 +133,7 @@ def main() -> None:
                     help="print the telemetry summary table (span "
                          "walls, counters, gauges) after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tel = None
     if args.trace or args.telemetry:

@@ -27,10 +27,11 @@ Measurement protocol per canonical-unique candidate:
      timed calls (``block_until_ready`` inside the stopwatch), record
      the median.
 
-The store fingerprint keys on the measuring platform
-(``jax.default_backend()``) in addition to the timing protocol: a CPU
-interpret-mode sweep and a TPU sweep of the same grid are different
-experiments and must never warm-start each other.
+The store fingerprint keys on the measuring device (platform, device
+kind and count — :func:`repro.engine.wallclock.device_identity`) in
+addition to the timing protocol: a CPU interpret-mode sweep and a TPU
+sweep of the same grid, or sweeps on two chip generations, are
+different experiments and must never warm-start each other.
 """
 from __future__ import annotations
 
@@ -43,7 +44,8 @@ import numpy as np
 from repro import obs
 from repro.core.costmodel import Machine
 from repro.engine.base import EvaluatorBase
-from repro.engine.wallclock import _as_output_map, assert_outputs_close
+from repro.engine.wallclock import (_as_output_map, assert_outputs_close,
+                                    device_identity)
 from repro.space.params import ParamSpace
 
 
@@ -57,7 +59,7 @@ class KernelWallclockEvaluator(EvaluatorBase):
                  noise_sigma: float = 0.0, noise_seed: int = 0, *,
                  repeats: int = 5, warmup: int = 1,
                  check_values: bool = True, rtol: float = 1e-4,
-                 atol: float = 1e-6, compile_mode: str = "batch",
+                 atol: float | None = None, compile_mode: str = "batch",
                  **base_kwargs):
         super().__init__(space, machine, noise_sigma, noise_seed,
                          **base_kwargs)
@@ -76,19 +78,20 @@ class KernelWallclockEvaluator(EvaluatorBase):
         self.warmup = max(1, warmup)
         self.check_values = check_values
         self.rtol = rtol
-        self.atol = atol
+        # The gate's absolute tolerance is the kernel's own
+        # (KernelRunner.atol) unless the caller overrides it.
+        self.atol = runner.atol if atol is None else atol
         self.compile_mode = compile_mode
         self.n_checked = 0
         self._reference: dict | None = None
 
     def _objective_key(self) -> str:
-        """Kernel wall clock is platform-specific on top of being
+        """Kernel wall clock is device-specific on top of being
         protocol-specific: CPU interpret-mode and TPU sweeps of the
         same grid must never share store entries. (``compile_mode`` is
         deliberately excluded — it moves compile cost around but the
         timed quantity is the same.)"""
-        import jax
-        return (f"kernel-wallclock:platform={jax.default_backend()}:"
+        return (f"kernel-wallclock:{device_identity()}:"
                 f"repeats={self.repeats}:warmup={self.warmup}")
 
     # -- reference outputs (computed lazily, once) -------------------------

@@ -311,6 +311,10 @@ def main(argv: "list[str] | None" = None) -> None:
 
     from repro.space import make_space
 
+    if args.backend == "wallclock":      # the one backend that compiles
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
+
     try:
         space = make_space(args.space, n_streams=args.n_streams) \
             if args.n_streams is not None else make_space(args.space)

@@ -50,6 +50,20 @@ def reference_schedule(graph: Graph) -> Schedule:
         for n in graph.topological_order()))
 
 
+def device_identity() -> str:
+    """The measuring hardware, as it goes into a wall-clock store key.
+
+    Platform, device kind and device count: ``jax.default_backend()``
+    alone says "tpu" for every chip generation, and a store filled on
+    one machine must never replay as a measurement of another.
+    """
+    import jax
+
+    devices = jax.devices()
+    return (f"platform={devices[0].platform}:"
+            f"kind={devices[0].device_kind}:count={len(devices)}")
+
+
 def _as_output_map(out) -> dict[str, np.ndarray]:
     """Normalize a runner's outputs (mapping / sequence / single array)
     to named numpy arrays for comparison."""
@@ -126,11 +140,13 @@ class ExecutorEvaluator(EvaluatorBase):
 
     def _objective_key(self) -> str:
         """Measured wall-clock time is machine- and protocol-specific:
-        never share store entries with the analytic family, nor with a
-        differently-configured timing protocol. Distinct impl/env sets
-        on the same graph should be disambiguated with ``store_tag=``.
+        never share store entries with the analytic family, with another
+        device (:func:`device_identity`), nor with a differently-
+        configured timing protocol. Distinct impl/env sets on the same
+        graph should be disambiguated with ``store_tag=``.
         """
-        return f"wallclock:repeats={self.repeats}:warmup={self.warmup}"
+        return (f"wallclock:{device_identity()}:"
+                f"repeats={self.repeats}:warmup={self.warmup}")
 
     # -- reference outputs (computed lazily, once) -------------------------
     def _reference_outputs(self) -> dict:
@@ -190,7 +206,10 @@ def demo_spmv_impls(graph: Graph, n: int = 16, seed: int = 0
 
     Small enough that a wall-clock smoke search finishes in seconds on
     CPU; the dataflow (pack -> send -> recv-wait -> remote multiply)
-    matches the DAG, so the value-correctness gate is meaningful.
+    matches the DAG, so the value-correctness gate is meaningful. The
+    matrices travel in ``env`` as runner inputs: closed over, they
+    would be baked into every schedule's executable as constants
+    (2 x 64 MiB at ``n=4096``), and each compile would pay for them.
     """
     import jax.numpy as jnp
 
@@ -208,7 +227,7 @@ def demo_spmv_impls(graph: Graph, n: int = 16, seed: int = 0
         "WaitSend": op_impl(lambda w: w, ["wire"], ["sent"]),
         "WaitRecv": op_impl(lambda w, r: w + r, ["wire", "recvbuf"],
                             ["xR"]),
-        "yL": op_impl(lambda x: AL @ x, ["xL"], ["yL"]),
-        "yR": op_impl(lambda x: AR @ x, ["xR"], ["yR"]),
+        "yL": op_impl(lambda a, x: a @ x, ["AL", "xL"], ["yL"]),
+        "yR": op_impl(lambda a, x: a @ x, ["AR", "xR"], ["yR"]),
     }
-    return impls, {"xL": xL}
+    return impls, {"xL": xL, "AL": AL, "AR": AR}

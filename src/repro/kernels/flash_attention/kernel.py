@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -80,7 +82,7 @@ def _flash_body(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128, scale: float | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (BH, Sq, D); k, v: (BH, Skv, D) — heads pre-flattened.
 
     Sq/Skv must divide by the block sizes (ops.py pads); causal
@@ -114,5 +116,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),   # output accum
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

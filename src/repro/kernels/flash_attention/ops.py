@@ -12,10 +12,6 @@ from repro.kernels.flash_attention.ref import attention_ref
 __all__ = ["mha", "attention_ref"]
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret"))
@@ -27,7 +23,6 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Pads Sq/Skv up to the block sizes (padded kv masked by position,
     padded q rows sliced off) and D up to the 128-lane tile.
     """
-    interpret = _interpret_default() if interpret is None else interpret
     b, h, sq, d = q.shape
     skv = k.shape[2]
     pq = (-sq) % block_q

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 LANES = 128
 
 
@@ -47,7 +49,7 @@ def _pack_body(idx_ref, x_ref, out_ref, *, chunk: int, n_chunks: int,
 @functools.partial(jax.jit,
                    static_argnames=("block_c", "chunk", "interpret"))
 def pack(x: jax.Array, idx: jax.Array, block_c: int = 256,
-         chunk: int = 1024, interpret: bool = True) -> jax.Array:
+         chunk: int = 1024, interpret: bool | None = None) -> jax.Array:
     """Gather x[idx] with the chunked one-hot kernel."""
     n = x.shape[0]
     m = idx.shape[0]
@@ -67,6 +69,6 @@ def pack(x: jax.Array, idx: jax.Array, block_c: int = 256,
         ],
         out_specs=pl.BlockSpec((1, block_c), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((1, mp), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx_p, x_p)
     return out[0, :m].astype(x.dtype)

@@ -11,12 +11,7 @@ from repro.kernels.pack.ref import pack_ref
 __all__ = ["pack", "pack_ref"]
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pack(x: jax.Array, idx: jax.Array,
          interpret: bool | None = None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
     return _pack_kernel(x, idx, interpret=interpret)

@@ -9,6 +9,7 @@ is wrong. The TPU-native decomposition is:
     layout: vals_t (K, N) so the short K axis sits on sublanes and the
     long row axis on lanes. The gather itself is done by XLA's gather HLO
     (efficient on TPU for VMEM/HBM-resident vectors) in the ops wrapper.
+    ``block_n`` is a lane dim: Mosaic accepts multiples of 128 only.
 
   * ``ell_onehot_mv`` — a fully in-kernel variant for *narrow-band*
     matrices: each row-block's columns fall in a width-W window, so the
@@ -26,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 LANES = 128
 SUBLANES = 8
@@ -47,7 +50,8 @@ def _mulsum_body(vals_ref, xg_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def ell_mulsum(vals_t: jax.Array, xg_t: jax.Array,
-               block_n: int = 512, interpret: bool = True) -> jax.Array:
+               block_n: int = 512,
+               interpret: bool | None = None) -> jax.Array:
     """y (N,) = sum over K of vals_t (K, N) * xg_t (K, N).
 
     K is padded to the sublane tile, N to ``block_n`` (lane-aligned).
@@ -68,7 +72,7 @@ def ell_mulsum(vals_t: jax.Array, xg_t: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(vals_p, xg_p)
     return out[0, :n]
 
@@ -79,7 +83,7 @@ def ell_mulsum(vals_t: jax.Array, xg_t: jax.Array,
 
 def _onehot_body(vals_ref, cols_ref, xwin_ref, y_ref, *, block_r: int,
                  window: int, k: int):
-    xw = xwin_ref[0, :].astype(jnp.float32)          # (W,)
+    xw = xwin_ref[0, 0, :].astype(jnp.float32)       # (W,)
     acc = jnp.zeros((block_r,), jnp.float32)
     iota = jax.lax.broadcasted_iota(jnp.int32, (block_r, window), 1)
     for kk in range(k):  # K is small and static: unrolled
@@ -90,25 +94,27 @@ def _onehot_body(vals_ref, cols_ref, xwin_ref, y_ref, *, block_r: int,
             onehot, xw[:, None], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)[:, 0]
         acc = acc + v * gathered
-    y_ref[...] = acc[None, :]
+    y_ref[...] = acc[None, None, :]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_r", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
 def ell_onehot_mv(vals_t: jax.Array, cols_win_t: jax.Array,
                   x_windows: jax.Array, block_r: int = 256,
-                  window: int | None = None,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """Narrow-band SpMV with in-kernel one-hot gather.
 
     vals_t / cols_win_t: (K, N) ELL-T; cols are *window-relative* per row
     block (see ops.ell_matvec_onehot). x_windows: (N // block_r, W) — the
     width-W slice of (wrap-padded) x covering each row block's columns.
+
+    The per-block window and output row carry a unit middle axis, so each
+    block's last two dims equal the array's ((1, W) and (1, block_r)):
+    Mosaic needs the second-minor block dim to be a multiple of 8 or the
+    whole dim, which a (1, W) block of an (nblocks, W) array is not.
     """
     k, n = vals_t.shape
     nblocks, w = x_windows.shape
     assert n % block_r == 0 and nblocks == n // block_r
-    window = w if window is None else window
     kp = _round_up(max(k, 1), SUBLANES)
     vals_p = jnp.zeros((kp, n), vals_t.dtype).at[:k].set(vals_t)
     # Padding rows gather window slot 0 with val 0: harmless.
@@ -120,10 +126,11 @@ def ell_onehot_mv(vals_t: jax.Array, cols_win_t: jax.Array,
         in_specs=[
             pl.BlockSpec((kp, block_r), lambda b: (0, b)),
             pl.BlockSpec((kp, block_r), lambda b: (0, b)),
-            pl.BlockSpec((1, w), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, w), lambda b: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_r), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, block_r), jnp.float32),
-        interpret=interpret,
-    )(vals_p, cols_p, x_windows)
+        out_specs=pl.BlockSpec((1, 1, block_r), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblocks, 1, block_r),
+                                       jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(vals_p, cols_p, x_windows.reshape(nblocks, 1, w))
     return out.reshape(n)

@@ -12,10 +12,6 @@ from repro.kernels.spmv.ref import ell_matvec_ref  # re-export for callers
 __all__ = ["ell_matvec", "ell_matvec_onehot", "ell_matvec_ref"]
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def ell_matvec(vals: jax.Array, cols: jax.Array, x: jax.Array,
                block_n: int = 512,
@@ -23,10 +19,11 @@ def ell_matvec(vals: jax.Array, cols: jax.Array, x: jax.Array,
     """y = A x for ELL (vals, cols) row-major (N, K) and dense x.
 
     Gather via XLA's gather HLO (TPU-native for wide/irregular column
-    sets), fused multiply-reduce in Pallas (ELL-T layout).
+    sets), fused multiply-reduce in Pallas (ELL-T layout). The gather
+    takes K-major indices: with row-major (N, K) indices, XLA's TPU
+    compiler spends over a minute on the paper's 150k x 10 matrix.
     """
-    interpret = _interpret_default() if interpret is None else interpret
-    xg_t = x[cols].T          # (K, N)
+    xg_t = x[cols.T]          # (K, N)
     vals_t = vals.T
     return _k.ell_mulsum(vals_t, xg_t, block_n=block_n,
                          interpret=interpret)
@@ -43,7 +40,6 @@ def ell_matvec_onehot(vals: jax.Array, cols: jax.Array, x: jax.Array,
     Valid when every column is within ``half_bandwidth`` of its row
     (circular metric). Window width = 2*half_bandwidth + block_r.
     """
-    interpret = _interpret_default() if interpret is None else interpret
     n, k = vals.shape
     hb = half_bandwidth
     pad_n = (-n) % block_r

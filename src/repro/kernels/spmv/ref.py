@@ -9,9 +9,10 @@ def ell_matvec_ref(vals: jnp.ndarray, cols: jnp.ndarray,
     """y[i] = sum_k vals[i, k] * x[cols[i, k]].
 
     Padding convention: padded entries have vals == 0 (cols may point
-    anywhere valid), so they contribute nothing.
+    anywhere valid), so they contribute nothing. The gather takes
+    K-major indices (see ``ops.ell_matvec``); the values are the same.
     """
-    return jnp.sum(vals * x[cols], axis=1)
+    return jnp.sum(vals * x[cols.T].T, axis=1)
 
 
 def ell_matvec_f32_ref(vals, cols, x):

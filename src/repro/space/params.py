@@ -74,11 +74,13 @@ class KernelRunner:
     problem instance (inputs are closed over — the instance is part of
     the space, hashed via the space ``signature``). ``reference()``
     returns the ground-truth outputs every candidate must reproduce
-    (the wallclock value-correctness gate).
+    (the wallclock value-correctness gate), within ``atol`` — the
+    absolute accuracy this kernel keeps against that reference.
     """
 
     build: Callable[[dict], Callable[[], Any]]
     reference: Callable[[], Any]
+    atol: float = 1e-6
 
 
 class _ParamBasis:

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.dist.compat import shard_map
 from repro.kernels.spmv import ops as spmv_ops
 
 AXIS = "ranks"
@@ -32,8 +31,7 @@ AXIS = "ranks"
 
 def _halo_exchange(x_block: jax.Array, axis: str = AXIS) -> jax.Array:
     """Assemble halo = [left neighbor block, right neighbor block]."""
-    # lax.axis_size is missing on older jax; psum(1) is its identity.
-    n = getattr(lax, "axis_size", lambda a: lax.psum(1, a))(axis)
+    n = lax.axis_size(axis)
     # perm (i -> i+1) means device j receives from j-1: its LEFT neighbor.
     from_left = lax.ppermute(x_block, axis,
                              [(i, (i + 1) % n) for i in range(n)])
@@ -77,7 +75,7 @@ def make_distributed_spmv(mesh: Mesh, *, use_kernel: bool = True,
     spec = P(AXIS)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=spec,
         # pallas_call outputs carry no varying-mesh-axis metadata yet.

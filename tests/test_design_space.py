@@ -211,7 +211,8 @@ def test_param_space_fingerprints_separate_everything(grid):
     other_sig.signature = "different-instance"
     fps = {
         grid.fingerprint(m, {}, "analytic"),
-        grid.fingerprint(m, {}, "kernel-wallclock:platform=cpu"),
+        grid.fingerprint(m, {}, "kernel-wallclock:platform=cpu:kind=cpu:"
+                                "count=1:repeats=5:warmup=1"),
         grid.fingerprint(Machine(flops_per_s=1e12), {}, "analytic"),
         other_dims.fingerprint(m, {}, "analytic"),
         other_sig.fingerprint(m, {}, "analytic"),

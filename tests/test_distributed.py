@@ -49,7 +49,7 @@ def test_compressed_dp_sync_bounded_error():
     run_sub("""
 import numpy as np, jax, jax.numpy as jnp, functools
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.dist.compress import compressed_psum_mean, init_ef, psum_mean
 mesh = Mesh(np.array(jax.devices()), ("data",))
 g_local = jnp.arange(8 * 64, dtype=jnp.float32).reshape(8, 64) / 1000.0

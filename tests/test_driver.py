@@ -459,10 +459,7 @@ def test_streaming_histogram_matches_numpy():
     assert h.n == len(vals)
 
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # tier-1 container
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 @settings(max_examples=40, deadline=None)

@@ -7,15 +7,18 @@ whole file stays in tier-1 budgets; the same code paths drive a real
 TPU sweep by constructing the spaces with bigger shapes and
 ``interpret=None``.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.engine as E
 import repro.search as S
+from repro.core import spmv_dag
 from repro.engine.params import KernelWallclockEvaluator
 from repro.kernels.autotune import (flash_attention_space, pack_space,
                                     spmv_mulsum_space)
+from repro.kernels.flash_attention import kernel as fa_kernel
 from repro.rules import distill
 from repro.rules.labels import Labeling
 from repro.space import KernelRunner, ParamSpace
@@ -129,6 +132,29 @@ def test_gate_error_names_the_candidate():
         ev.evaluate([(32,)])
 
 
+def test_gate_tolerance_is_the_runners_unless_overridden():
+    honest = _spmv_grid(block_values=(32,))
+
+    def off_by(tol_atol):
+        return ParamSpace(honest.name, honest.dims,
+                          runner=KernelRunner(
+                              build=lambda p: lambda: honest.runner.build(
+                                  p)() + 1e-3,
+                              reference=honest.runner.reference,
+                              atol=tol_atol),
+                          signature=honest.signature + ":off-by-1e-3")
+
+    strict = E.make_evaluator(off_by(1e-6), "wallclock", repeats=1)
+    with pytest.raises(AssertionError, match="value-correctness gate"):
+        strict.evaluate([(32,)])
+    loose = E.make_evaluator(off_by(1e-2), "wallclock", repeats=1)
+    assert loose.evaluate([(32,)])[0] > 0.0 and loose.n_checked == 1
+    override = E.make_evaluator(off_by(1e-2), "wallclock", repeats=1,
+                                atol=1e-6)
+    with pytest.raises(AssertionError, match="value-correctness gate"):
+        override.evaluate([(32,)])
+
+
 def test_check_values_off_skips_the_gate():
     honest = _spmv_grid(block_values=(32,))
     broken = ParamSpace(honest.name, honest.dims,
@@ -146,8 +172,18 @@ def test_platform_is_part_of_the_objective_key():
     sp = _spmv_grid()
     ev = E.make_evaluator(sp, "wallclock", repeats=3, warmup=2)
     key = ev._objective_key()
-    assert key.startswith("kernel-wallclock:platform=")
-    assert key.endswith(":repeats=3:warmup=2")
+    dev = jax.devices()[0]
+    # Device kind and count, not just the backend name: "tpu" alone
+    # would let a store filled on one chip generation serve another.
+    device = (f"platform={dev.platform}:kind={dev.device_kind}:"
+              f"count={jax.device_count()}")
+    assert key == f"kernel-wallclock:{device}:repeats=3:warmup=2"
+    # The schedule-space wallclock backend keys on the same identity.
+    impls, env = E.demo_spmv_impls(spmv_dag())
+    sched_ev = E.make_evaluator(spmv_dag(), "wallclock", impls=impls,
+                                env=env)
+    assert sched_ev._objective_key() == \
+        f"wallclock:{device}:repeats=5:warmup=1"
     # compile_mode moves compile cost around but measures the same
     # quantity — deliberately NOT in the key.
     ev2 = E.make_evaluator(sp, "wallclock", repeats=3, warmup=2,
@@ -248,6 +284,53 @@ def test_flash_attention_autotune_distills_block_size_rules(tmp_path):
     _, warm = run()
     assert (warm.store_hits, warm.cache_misses) == (9, 0)  # 100% warm
     assert warm.times == cold.times
+
+
+def _mask_dropped(body):
+    return lambda *refs, **kw: body(*refs, **{**kw, "causal": False})
+
+
+def _mask_off_by_one(body):
+    return lambda *refs, **kw: body(
+        *refs, **{**kw, "q_offset": kw["q_offset"] + 1})
+
+
+def _state_not_carried(body):
+    def faulty(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, **kw):
+        m_scr[...] = jnp.full_like(m_scr, fa_kernel.NEG_INF)
+        body(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, **kw)
+    return faulty
+
+
+@pytest.fixture
+def planted_flash_fault(monkeypatch):
+    """Swap the flash kernel's body for a faulty one; the jit caches
+    are cleared on both sides so no faulty trace outlives the test."""
+    def plant(fault):
+        monkeypatch.setattr(fa_kernel, "_flash_body",
+                            fault(fa_kernel._flash_body))
+        jax.clear_caches()
+    yield plant
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("fault", [_mask_dropped, _mask_off_by_one,
+                                   _state_not_carried])
+def test_flash_gate_rejects_planted_faults(planted_flash_fault, fault):
+    """The flash space's gate tolerance (atol 2e-2, set for the chip's
+    bf16-pass dots) still rejects wrong masking and a lost online-
+    softmax state, each far outside it."""
+    sp = flash_attention_space(batch=1, heads=2, seq=64, head_dim=16,
+                               block_values=(16,), interpret=True)
+    ref = np.asarray(sp.runner.reference())
+    planted_flash_fault(fault)
+    err = np.abs(np.asarray(sp.runner.build({"block_q": 16,
+                                             "block_k": 16})()) - ref)
+    assert err.max() > 10 * sp.runner.atol
+    ev = E.make_evaluator(sp, "wallclock", repeats=1)
+    with pytest.raises(AssertionError, match="value-correctness gate"):
+        ev.evaluate([(16, 16)])
 
 
 def test_pack_space_smallest_grid_round_trip():

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # tier-1 container: seeded-random fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.pack import ops as pack_ops
 from repro.kernels.spmv import ops as spmv_ops

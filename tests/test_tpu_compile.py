@@ -1,0 +1,96 @@
+"""Ahead-of-time compiles of the main path's kernels for one TPU v5e chip.
+
+Nothing runs: each case lowers and compiles at a deployment shape for a
+described (not attached) v5e topology, so the chip's compiler refuses
+here what interpret mode cannot see — block tilings, VMEM limits. The
+CPU backend still answers ``jax.default_backend()``, so every kernel is
+called with ``interpret=False``. The topology is described inside a
+fixture, never at import: only the worker that runs this file loads
+the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import spmv_dag
+from repro.core.executor import build_runner
+from repro.engine.wallclock import demo_spmv_impls, reference_schedule
+from repro.kernels.flash_attention.ops import mha
+from repro.kernels.pack.kernel import pack
+from repro.kernels.spmv.kernel import ell_mulsum
+from repro.kernels.spmv.ops import ell_matvec_onehot
+
+PAPER_N, PAPER_K = 150_000, 10          # the paper's matrix (spmv/matrix.py)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def shape(one_chip):
+    def make(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return make
+
+
+def _kernel_compiled(lowered):
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text       # a Mosaic kernel, not interpreted
+    return text
+
+
+@pytest.mark.parametrize("block_n", [128, 512])
+def test_ell_mulsum_compiles_at_paper_size(shape, block_n):
+    kt = shape((PAPER_K, PAPER_N))
+    _kernel_compiled(ell_mulsum.lower(kt, kt, block_n=block_n,
+                                      interpret=False))
+
+
+def test_ell_mulsum_refuses_unaligned_lane_block(shape):
+    kt = shape((PAPER_K, PAPER_N))
+    with pytest.raises(Exception, match="128"):
+        ell_mulsum.lower(kt, kt, block_n=64, interpret=False).compile()
+
+
+def test_ell_onehot_compiles_narrow_band(shape):
+    n, k = 65_536, PAPER_K
+    _kernel_compiled(ell_matvec_onehot.lower(
+        shape((n, k)), shape((n, k), jnp.int32), shape((n,)),
+        half_bandwidth=128, block_r=256, interpret=False))
+
+
+def test_flash_attention_compiles_at_smollm_width(shape):
+    qkv = shape((1, 15, 2048, 64))        # smollm-360m: 15 heads x 64
+    _kernel_compiled(mha.lower(qkv, qkv, qkv, causal=True, block_q=128,
+                               block_k=128, interpret=False))
+
+
+def test_pack_compiles(shape):
+    _kernel_compiled(pack.lower(shape((PAPER_N,)),
+                                shape((4096,), jnp.int32),
+                                interpret=False))
+
+
+def test_token_chain_runner_compiles(shape):
+    g = spmv_dag()
+    impls, env = demo_spmv_impls(g, n=256)
+    run = build_runner(g, reference_schedule(g), impls)
+    env_shapes = {k: shape(v.shape, v.dtype) for k, v in env.items()}
+    compiled = jax.jit(run).lower(env_shapes).compile()
+    assert compiled.memory_analysis() is not None
