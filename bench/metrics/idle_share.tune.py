@@ -1,0 +1,6 @@
+"""Share of the window in which no operation ran on the device,
+averaged over the chips."""
+
+
+def read(ctx):
+    return ctx.trace.idle_share()

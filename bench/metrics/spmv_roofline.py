@@ -1,0 +1,16 @@
+"""The SpMV call's share of its roofline: the least time the work
+counts allow on the peak table's bandwidth, over the mean device time
+of the benchmark-jitted call (per call, its slowest chip).
+
+A trace with no TPU plane (a rehearsal on the CPU) gives nothing to
+read; a TPU trace without the benchmark's own module is an error."""
+
+
+def read(ctx):
+    if not ctx.trace.devices:
+        return None
+    runs = ctx.trace.slowest_run_s(ctx.unit.module)
+    if not runs:
+        raise LookupError(f"no run of {ctx.unit.module} on the device in "
+                          "the traced window")
+    return 100.0 * ctx.unit.least_time_s() / (sum(runs) / len(runs))
