@@ -160,7 +160,6 @@ class SearchDriver:
             with obs.span("driver.propose", round=self._round):
                 pool = s.propose_pool(ask)
             if pool is not None:
-                obs.counter("driver.pool_size").add(len(pool))
                 with obs.span("driver.acquire", round=self._round,
                               pool=len(pool)):
                     chosen = s.screen(pool, ask, self.acquisition)
@@ -254,10 +253,6 @@ class SearchDriver:
                                 sink.consume(eb, fresh)
                         n_fresh = int(np.count_nonzero(fresh))
                         if tel.enabled:
-                            tel.counter("driver.proposed").add(len(batch))
-                            tel.counter("driver.fresh").add(n_fresh)
-                            tel.counter("driver.fresh_evals").add(
-                                ev.fresh_evals() - batch_fresh0)
                             if len(eb) and float(np.min(eb.times)) < best:
                                 best = float(np.min(eb.times))
                                 tel.gauge("driver.best").set(best)

@@ -27,6 +27,14 @@ Measurement protocol per canonical-unique candidate:
      timed calls (``block_until_ready`` inside the stopwatch), record
      the median.
 
+Spans (:mod:`repro.obs`): ``kernel.compile`` and ``kernel.timing``
+around the phases, each with the ``gate_s`` spent in it; one
+``kernel.build`` per candidate around its build and first call; and
+the gate in three parts, ``kernel.reference`` (once per evaluator),
+``kernel.fetch`` (the output's copy to the host) and
+``kernel.compare`` (the tolerance check). No timed call is inside a
+span of its own.
+
 The store fingerprint keys on the measuring device (platform, device
 kind and count — :func:`repro.engine.wallclock.device_identity`) in
 addition to the timing protocol: a CPU interpret-mode sweep and a TPU
@@ -37,6 +45,7 @@ from __future__ import annotations
 
 import statistics
 import time
+from collections.abc import Mapping
 from typing import Sequence
 
 import numpy as np
@@ -97,16 +106,25 @@ class KernelWallclockEvaluator(EvaluatorBase):
     # -- reference outputs (computed lazily, once) -------------------------
     def _reference_outputs(self) -> dict:
         if self._reference is None:
-            self._reference = _as_output_map(self.runner.reference())
+            with obs.span("kernel.reference") as sp:
+                self._reference = _as_output_map(self.runner.reference())
+                sp.set(bytes=_nbytes(self._reference))
         return self._reference
 
     def _check(self, out, candidate) -> None:
-        assert_outputs_close(
-            out, self._reference_outputs(), rtol=self.rtol,
-            atol=self.atol,
-            context=(f" for candidate "
-                     f"({self.space.describe(candidate)}) — kernel "
-                     "output failed the value-correctness gate"))
+        # The gate in three spans: the reference (its first use only),
+        # the output's copy to the host, and the tolerance check.
+        ref = self._reference_outputs()
+        with obs.span("kernel.fetch") as sp:
+            got = _as_output_map(out)
+            n = _nbytes(got)
+            sp.set(bytes=n)
+        with obs.span("kernel.compare", bytes=n):
+            assert_outputs_close(
+                got, ref, rtol=self.rtol, atol=self.atol,
+                context=(f" for candidate "
+                         f"({self.space.describe(candidate)}) — kernel "
+                         "output failed the value-correctness gate"))
         self.n_checked += 1
 
     def _measure_batch(self, candidates: Sequence,
@@ -128,25 +146,37 @@ class KernelWallclockEvaluator(EvaluatorBase):
             self._check(result, cand)
             gate_s += time.perf_counter() - g0
 
+        def _first_call(cand, run=None):
+            # kernel.build: the runner's build where it is still to
+            # come, then the first call: the trace, the compile (or the
+            # load from the persistent cache) and the first execution.
+            with obs.span("kernel.build") as sp:
+                if run is None:
+                    run = self.runner.build(self.space.as_dict(cand))
+                result = jax.block_until_ready(run())
+                sp.set(bytes=_nbytes(result))
+            return run, result
+
         try:
             runs = []
             with obs.span("kernel.compile", n=len(candidates),
                           mode=self.compile_mode) as compile_span:
                 for cand in candidates:
-                    run = self.runner.build(self.space.as_dict(cand))
-                    runs.append(run)
                     if self.compile_mode == "batch":
                         # Compile + gate the whole batch ahead of timing.
-                        result = jax.block_until_ready(run())
+                        run, result = _first_call(cand)
                         if self.check_values:
                             _gated_check(result, cand)
+                    else:
+                        run = self.runner.build(self.space.as_dict(cand))
+                    runs.append(run)
                 compile_span.set(gate_s=gate_s)
             compile_gate_s = gate_s
             with obs.span("kernel.timing", n=len(candidates),
                           repeats=self.repeats) as timing_span:
                 for cand, run in zip(candidates, runs):
                     if self.compile_mode == "per_candidate":
-                        result = jax.block_until_ready(run())
+                        _, result = _first_call(cand, run)
                         if self.check_values:
                             _gated_check(result, cand)
                     for _ in range(self.warmup - 1):
@@ -158,8 +188,6 @@ class KernelWallclockEvaluator(EvaluatorBase):
                         times.append(time.perf_counter() - t0)
                     out.append(statistics.median(times))
                 timing_span.set(gate_s=gate_s - compile_gate_s)
-            if self.check_values:
-                obs.counter("kernel.gate_checks").add(len(candidates))
         finally:
             # Same salvage contract as the executor backend: if a
             # candidate fails the value gate mid-batch, the timings
@@ -168,3 +196,13 @@ class KernelWallclockEvaluator(EvaluatorBase):
             if encoded is not None and len(out) < len(candidates):
                 self._salvage_partial(encoded[:len(out)], out)
         return out
+
+
+def _nbytes(out) -> int:
+    """Bytes of a runner's outputs: an array, or a mapping or sequence
+    of arrays."""
+    if isinstance(out, Mapping):
+        out = list(out.values())
+    if isinstance(out, (tuple, list)):
+        return sum(int(getattr(v, "nbytes", 0)) for v in out)
+    return int(getattr(out, "nbytes", 0))

@@ -130,19 +130,21 @@ class EvalServer:
             conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             mtype, body = recv_frame(conn)
             if mtype != MSG_HELLO:
+                # Counted before the refusal is sent: a client that
+                # reads the meter after its refusal must see it.
+                self.n_refused += 1
                 send_frame(conn, encode_refuse(
                     f"expected HELLO, got message type {mtype}"))
-                self.n_refused += 1
                 return
             fp = decode_hello(body)
             mine = self.evaluator.store_fingerprint
             if fp != mine:
+                self.n_refused += 1
                 send_frame(conn, encode_refuse(
                     f"fingerprint mismatch: client {fp.hex()} vs "
                     f"server {mine.hex()} (space {self.space.name!r}, "
                     f"backend {self.backend!r}) — different graph, "
                     "machine, or objective"))
-                self.n_refused += 1
                 return
             send_frame(conn, encode_welcome({
                 "space": self.space.name, "backend": self.backend,

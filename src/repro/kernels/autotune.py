@@ -9,14 +9,15 @@ kernel's reference implementation, batch-ahead compilation, persistent
 :class:`~repro.engine.store.EvalStore` warm starts) and distilled into
 per-platform block-size design rules by :func:`repro.rules.distill`.
 
-Each factory closes the kernel over one fixed, seeded problem instance
-(the instance is part of the space — its shape/seed go into the
-``signature`` hashed by the store fingerprint, so measurements from
-different instances never alias). Shapes default small enough that the
-interpret-mode (CPU) sweep stays in test budgets; pass bigger ones for
-a real tuning run on TPU. ``interpret=None`` (the default) compiles
-the kernels everywhere but on the CPU backend
-(:func:`repro.kernels.resolve_interpret`).
+Each factory closes the kernel over one fixed, seeded problem instance,
+drawn on the host (a ``space.instance`` span) and then copied to the
+device (``space.put``). The instance is part of the space — its
+shape/seed go into the ``signature`` hashed by the store fingerprint,
+so measurements from different instances never alias. Shapes default
+small enough that the interpret-mode (CPU) sweep stays in test
+budgets; pass bigger ones for a real tuning run on TPU.
+``interpret=None`` (the default) compiles the kernels everywhere but
+on the CPU backend (:func:`repro.kernels.resolve_interpret`).
 
 These constructors import JAX; :mod:`repro.space` registers them
 lazily (``make_space("flash_attention")``) so the protocol layer stays
@@ -26,9 +27,20 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.space.params import KernelRunner, ParamSpace
 
 __all__ = ["flash_attention_space", "spmv_mulsum_space", "pack_space"]
+
+
+def _put(*host: np.ndarray) -> tuple:
+    """``space.put``: the instance's copy to the device, as issued. The
+    copy may run on past the span, beside the evaluator's set-up; the
+    first call that reads the arrays waits for it."""
+    import jax.numpy as jnp
+
+    with obs.span("space.put", bytes=sum(a.nbytes for a in host)):
+        return tuple(jnp.asarray(a) for a in host)
 
 
 def _divisors_of(seq: int, values) -> tuple[int, ...]:
@@ -52,19 +64,17 @@ def flash_attention_space(*, batch: int = 1, heads: int = 2,
     unpadded paths measure the same problem (and causal right-aligned
     masking needs equal q/kv padding anyway).
     """
-    import jax.numpy as jnp
-
     from repro.kernels.flash_attention.ops import mha
     from repro.kernels.flash_attention.ref import attention_ref
 
     blocks = _divisors_of(seq, block_values)
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal(
-        (batch, heads, seq, head_dim)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal(
-        (batch, heads, seq, head_dim)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal(
-        (batch, heads, seq, head_dim)).astype(np.float32))
+    with obs.span("space.instance") as sp:
+        rng = np.random.default_rng(seed)
+        host = [rng.standard_normal(                # q, k, v in turn
+            (batch, heads, seq, head_dim)).astype(np.float32)
+            for _ in range(3)]
+        sp.set(bytes=sum(a.nbytes for a in host))
+    q, k, v = _put(*host)
 
     def build(params: dict):
         bq, bk = params["block_q"], params["block_k"]
@@ -106,16 +116,16 @@ def spmv_mulsum_space(*, n: int = 1024, k: int = 8,
     multiples of 128 only; any other value is refused by the chip's
     compiler, and that candidate fails its measurement loudly.
     """
-    import jax.numpy as jnp
-
     from repro.kernels.spmv.ops import ell_matvec
     from repro.kernels.spmv.ref import ell_matvec_ref
     from repro.spmv.matrix import band_matrix
 
-    a = band_matrix(n, n * k, seed=seed)
-    vals, cols = jnp.asarray(a.vals), jnp.asarray(a.cols)
-    x = jnp.asarray(np.random.default_rng(seed).standard_normal(
-        n).astype(np.float32))
+    with obs.span("space.instance") as sp:
+        a = band_matrix(n, n * k, seed=seed)
+        x = np.random.default_rng(seed).standard_normal(n).astype(
+            np.float32)
+        sp.set(bytes=a.vals.nbytes + a.cols.nbytes + x.nbytes)
+    vals, cols, x = _put(a.vals, a.cols, x)
 
     def build(params: dict):
         bn = params["block_n"]
@@ -147,14 +157,15 @@ def pack_space(*, n: int = 4096, m: int = 512,
     wrapper pins the kernel defaults) — a winning rule here is exactly
     what that wrapper should adopt per platform.
     """
-    import jax.numpy as jnp
-
     from repro.kernels.pack.kernel import pack as pack_kernel
     from repro.kernels.pack.ref import pack_ref
 
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-    idx = jnp.asarray(rng.integers(0, n, size=m).astype(np.int32))
+    with obs.span("space.instance") as sp:
+        rng = np.random.default_rng(seed)
+        x = rng.standard_normal(n).astype(np.float32)
+        idx = rng.integers(0, n, size=m).astype(np.int32)
+        sp.set(bytes=x.nbytes + idx.nbytes)
+    x, idx = _put(x, idx)
 
     def build(params: dict):
         bc, ch = params["block_c"], params["chunk"]

@@ -1,6 +1,6 @@
 """Telemetry exporters: where the event stream lands.
 
-Three formats, one contract — ``export(event: dict)`` per event plus a
+Two formats, one contract — ``export(event: dict)`` per event plus a
 ``close()`` flush. Events are the Chrome trace-event shape the
 registry emits (:mod:`repro.obs.telemetry`): ``ph`` is ``"B"``/``"E"``
 (span begin/end), ``"C"`` (counter/gauge sample), or ``"i"`` (instant);
@@ -11,10 +11,6 @@ registry emits (:mod:`repro.obs.telemetry`): ``ph`` is ``"B"``/``"E"``
     straight into https://ui.perfetto.dev — spans nest per thread
     track, counters render as value tracks. Buffered in memory, written
     atomically at :meth:`close`.
-:class:`JsonlExporter`
-    One JSON object per line, streamed as events happen — the
-    grep/pandas-friendly event log, and the crash-tolerant one (a
-    killed run keeps every line already flushed).
 :class:`MemoryExporter`
     In-process event list, for tests and programmatic consumers.
 """
@@ -47,35 +43,13 @@ class MemoryExporter:
         pass
 
 
-class JsonlExporter:
-    """Stream events as JSON lines to ``path`` (appending never; a new
-    run truncates — one file is one run's event log)."""
-
-    def __init__(self, path: "str | os.PathLike"):
-        self.path = os.fspath(path)
-        parent = os.path.dirname(self.path)
-        if parent:
-            os.makedirs(parent, exist_ok=True)
-        self._f = open(self.path, "w")
-
-    def export(self, event: dict) -> None:
-        if self._f is not None:
-            self._f.write(json.dumps(event) + "\n")
-
-    def close(self) -> None:
-        if self._f is not None:
-            self._f.close()
-            self._f = None
-
-
 class PerfettoExporter:
     """Chrome trace-event / Perfetto JSON.
 
     Events buffer in memory and are written as one
     ``{"traceEvents": [...], "displayTimeUnit": "ms"}`` document at
     :meth:`close` (atomic rename, so a crashed run leaves no
-    half-written trace — use :class:`JsonlExporter` alongside when
-    crash-time events matter more than loadability).
+    half-written trace).
     """
 
     def __init__(self, path: "str | os.PathLike"):
@@ -101,17 +75,13 @@ class PerfettoExporter:
 
 
 def load_trace(path: "str | os.PathLike") -> list[dict]:
-    """Read a trace back as an event list — both exporter formats.
+    """Read a trace back as an event list.
 
-    Accepts the Perfetto document shape (``{"traceEvents": [...]}``), a
-    bare JSON array, or JSONL. The schema-sanity test and the CI gate
-    read traces through this, so the check and the writer can never
-    drift apart silently.
+    Accepts the Perfetto document shape (``{"traceEvents": [...]}``) or
+    a bare JSON array. The schema-sanity test and the CI gate read
+    traces through this, so the check and the writer can never drift
+    apart silently.
     """
     with open(os.fspath(path)) as f:
-        text = f.read()
-    stripped = text.lstrip()
-    if stripped.startswith("{") or stripped.startswith("["):
-        doc = json.loads(text)
-        return doc["traceEvents"] if isinstance(doc, dict) else doc
-    return [json.loads(line) for line in text.splitlines() if line]
+        doc = json.load(f)
+    return doc["traceEvents"] if isinstance(doc, dict) else doc

@@ -17,6 +17,11 @@ process-wide place all of that lands:
   .add(n)`, ``gauge("driver.best").set(t)``); counter/gauge updates are
   also streamed as Chrome-trace ``"C"`` events so Perfetto renders them
   as tracks under the span timeline.
+* **The profiler's clock** — once JAX is imported, every span of an
+  enabled registry is also a ``jax.profiler.TraceAnnotation`` of the
+  same name, so a profiler trace shows the program's spans on its host
+  plane, on the clock of the device events. This module never imports
+  JAX itself: a search that never touches JAX never pays its start-up.
 
 **Telemetry is a pure observer.** Nothing in this module is ever read
 back by the instrumented code: timestamps never feed RNGs, cache keys,
@@ -41,6 +46,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from typing import TYPE_CHECKING
@@ -87,15 +93,22 @@ class Span:
     ``"E"`` event (attributes attached to the end event, where
     late-``set`` values are visible), and folds the wall into the
     registry's per-name aggregate. Exceptions propagate untouched.
+
+    Where JAX is already imported, the span also enters a
+    ``jax.profiler.TraceAnnotation`` of its name just inside its own
+    begin and leaves it just inside its end: a no-op unless a profiler
+    trace is running, and then the span's interval on the trace's host
+    plane.
     """
 
-    __slots__ = ("name", "attrs", "_tel", "_t0")
+    __slots__ = ("name", "attrs", "_tel", "_t0", "_mark")
 
     def __init__(self, name: str, tel: "Telemetry", attrs: dict):
         self.name = name
         self.attrs = attrs
         self._tel = tel
         self._t0 = 0
+        self._mark = None
 
     def set(self, **attrs) -> None:
         """Attach attributes discovered mid-span (e.g. batch meters)."""
@@ -104,9 +117,16 @@ class Span:
     def __enter__(self) -> "Span":
         self._t0 = time.perf_counter_ns()
         self._tel._begin(self)
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._mark = profiler.TraceAnnotation(self.name)
+            self._mark.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._mark is not None:
+            self._mark.__exit__(*exc)
+            self._mark = None
         self._tel._end(self, time.perf_counter_ns())
 
 
@@ -148,15 +168,16 @@ class Telemetry:
 
     ``exporters`` is any iterable of objects with an
     ``export(event: dict)`` method and a ``close()``
-    (:mod:`repro.obs.exporters` ships JSONL and Perfetto/Chrome-trace
-    implementations; an empty list keeps everything in-memory for the
-    :meth:`summary` table and the ``spans_by_name`` aggregate, which is
-    how tests and the CI warm-start gate read it).
+    (:mod:`repro.obs.exporters` ships Perfetto/Chrome-trace and
+    in-memory implementations; an empty list keeps everything in memory
+    for the :meth:`summary` table and the ``spans_by_name`` aggregate,
+    which is how tests and the CI warm-start gate read it).
 
     Timestamps are ``time.perf_counter_ns`` offsets from registry
     construction, exported in microseconds — monotone within a process,
     meaningless across processes (worker pools report through their
-    parent's meters, never their own registry).
+    parent's meters, never their own registry). The profiler's copy of
+    each span (see :class:`Span`) carries the profiler's own clock.
     """
 
     enabled = True

@@ -45,6 +45,7 @@ from typing import Any, Callable, Iterator, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.dag import Graph
 
 
@@ -230,14 +231,16 @@ def register_space(name: str,
 
 
 def make_space(name: str, **kwargs) -> DesignSpace:
-    """Construct a registered design space by name."""
+    """Construct a registered design space by name, inside a
+    ``space.make`` span."""
     try:
         factory = SPACES[name]
     except KeyError:
         raise ValueError(
             f"unknown design space {name!r}; registered: "
             f"{sorted(SPACES)}") from None
-    return factory(**kwargs)
+    with obs.span("space.make", space=name):
+        return factory(**kwargs)
 
 
 def as_space(obj, n_streams: int | None = None) -> DesignSpace:

@@ -14,6 +14,7 @@ import pytest
 
 import repro.engine as E
 import repro.search as S
+from repro import obs
 from repro.core import spmv_dag
 from repro.engine.params import KernelWallclockEvaluator
 from repro.kernels.autotune import (flash_attention_space, pack_space,
@@ -153,6 +154,84 @@ def test_gate_tolerance_is_the_runners_unless_overridden():
                                 atol=1e-6)
     with pytest.raises(AssertionError, match="value-correctness gate"):
         override.evaluate([(32,)])
+
+
+def _durations(events) -> dict[str, list[float]]:
+    """Seconds of each finished span, by name (B/E pairs, LIFO)."""
+    stack, out = [], {}
+    for e in events:
+        if e["ph"] == "B":
+            stack.append(e)
+        elif e["ph"] == "E":
+            out.setdefault(e["name"], []).append(
+                (e["ts"] - stack.pop()["ts"]) / 1e6)
+    return out
+
+
+@pytest.mark.parametrize("compile_mode", ["batch", "per_candidate"])
+def test_gate_spans_split_the_gate(compile_mode):
+    """One ``kernel.reference`` per evaluator; one ``kernel.build``,
+    ``kernel.fetch`` and ``kernel.compare`` per candidate; the gate's
+    three parts lie inside the ``gate_s`` the phases report."""
+    out = jnp.arange(8, dtype=jnp.float32)
+    stub = ParamSpace("stub", [("block", (1, 2, 3))],
+                      runner=KernelRunner(
+                          build=lambda p: lambda: out,
+                          reference=lambda: np.arange(8, dtype=np.float32)),
+                      signature="stub:arange8")
+    ex = obs.MemoryExporter()
+    with obs.use(obs.Telemetry(exporters=[ex])):
+        ev = E.make_evaluator(stub, "wallclock", repeats=1,
+                              compile_mode=compile_mode)
+        ev.evaluate([(1,), (2,)])
+        ev.evaluate([(3,)])                 # a second miss batch
+    ends = [e for e in ex.events if e["ph"] == "E"]
+    count = {name: sum(e["name"] == name for e in ends)
+             for name in ("kernel.reference", "kernel.build",
+                          "kernel.fetch", "kernel.compare")}
+    assert count == {"kernel.reference": 1, "kernel.build": 3,
+                     "kernel.fetch": 3, "kernel.compare": 3}
+    assert {e["args"]["bytes"] for e in ends
+            if e["name"] in ("kernel.reference", "kernel.build",
+                             "kernel.fetch", "kernel.compare")} == {32}
+    gate_s = sum(e["args"]["gate_s"] for e in ends
+                 if e["name"] in ("kernel.compile", "kernel.timing"))
+    dur = _durations(ex.events)
+    parts = sum(sum(dur[n]) for n in ("kernel.reference", "kernel.fetch",
+                                      "kernel.compare"))
+    assert 0.0 < parts <= gate_s + 1e-6
+    assert ev.n_checked == 3
+
+
+@pytest.mark.parametrize("name,kwargs", [
+    ("spmv_mulsum", dict(n=128, k=4, block_values=(32,))),
+    ("flash_attention", dict(batch=1, heads=1, seq=32, head_dim=16,
+                             block_values=(16,))),
+    ("pack", dict(n=256, m=64, block_c_values=(32,),
+                  chunk_values=(64,))),
+])
+def test_make_space_spans_the_draw_and_the_copy(name, kwargs):
+    """``space.make`` holds ``space.instance`` then ``space.put``, both
+    sized in bytes, and the space is the same byte for byte with the
+    telemetry on or off."""
+    from repro.space import make_space
+
+    plain = make_space(name, interpret=True, **kwargs)
+    ex = obs.MemoryExporter()
+    with obs.use(obs.Telemetry(exporters=[ex])):
+        traced = make_space(name, interpret=True, **kwargs)
+    assert [(e["ph"], e["name"]) for e in ex.events] == [
+        ("B", "space.make"), ("B", "space.instance"),
+        ("E", "space.instance"), ("B", "space.put"), ("E", "space.put"),
+        ("E", "space.make")]
+    sizes = {e["name"]: e["args"]["bytes"] for e in ex.events
+             if e["ph"] == "E" and e["name"] != "space.make"}
+    assert sizes["space.instance"] == sizes["space.put"] > 0
+    params = plain.as_dict(next(iter(plain.enumerate_candidates())))
+    for a, b in ((plain.runner.reference(), traced.runner.reference()),
+                 (plain.runner.build(params)(),
+                  traced.runner.build(params)())):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 def test_check_values_off_skips_the_gate():

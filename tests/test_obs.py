@@ -12,10 +12,18 @@ Locks the PR's acceptance criteria:
   with the evaluator's ``store_hits``;
 * ``TraceSink`` rounds carry their index, ``key_stream()`` keeps its
   flat back-compat shape, and the ``"telemetry"`` sink is registered;
+* an enabled registry's spans are mirrored, in LIFO order, as
+  ``jax.profiler`` annotations that land on a real trace's host plane;
+  the disabled one mirrors nothing, and ``repro.obs`` imports no JAX;
 * ``benchmarks/run.py``'s baseline comparator flags exactly the
   regressed rows.
 """
 import json
+import os
+import pathlib
+import subprocess
+import sys
+import time
 
 import numpy as np
 import pytest
@@ -101,6 +109,89 @@ def test_exception_inside_span_still_closes_it():
             with obs.span("fails"):
                 raise RuntimeError("boom")
     assert tel.spans_by_name()["fails"]["count"] == 1
+
+
+# -- the profiler's clock -----------------------------------------------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Swap ``jax.profiler.TraceAnnotation`` for a stand-in; returns the
+    log of its enters and exits."""
+    import jax
+
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return log
+
+
+def test_enabled_registry_mirrors_nested_spans_lifo(annotations):
+    with obs.use(obs.Telemetry()):
+        with obs.span("driver.run"):
+            with obs.span("kernel.fetch"):
+                pass
+            with pytest.raises(RuntimeError):
+                with obs.span("kernel.compare"):
+                    raise RuntimeError("gate failed")
+    assert annotations == [
+        ("enter", "driver.run"),
+        ("enter", "kernel.fetch"), ("exit", "kernel.fetch"),
+        ("enter", "kernel.compare"), ("exit", "kernel.compare"),
+        ("exit", "driver.run")]
+
+
+def test_disabled_registry_makes_no_annotations(annotations):
+    with obs.span("driver.run"):
+        with obs.span("kernel.fetch"):
+            pass
+    assert annotations == []
+
+
+def test_import_obs_imports_no_jax():
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "with obs.use(obs.Telemetry()):\n"
+            "    with obs.span('driver.run'):\n"
+            "        pass\n"
+            "assert 'jax' not in sys.modules, 'repro.obs imported jax'\n")
+    env = dict(os.environ, PYTHONPATH=str(src))
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+def test_profiler_trace_holds_program_spans(tmp_path):
+    """A real ``jax.profiler`` trace on the CPU: the span is an event of
+    the host plane, as long as the span."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.use(obs.Telemetry()):
+            with obs.span("kernel.compare", bytes=8):
+                time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    found = [(e.start_ns, e.end_ns) for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == "kernel.compare"]
+    assert len(found) == 1
+    assert found[0][1] - found[0][0] >= 5e6
 
 
 # -- pure observer: byte-identity with exporters attached ---------------------
