@@ -27,13 +27,26 @@ Measurement protocol per canonical-unique candidate:
      timed calls (``block_until_ready`` inside the stopwatch), record
      the median.
 
+The value gate compares each candidate's first output with the
+reference where both are. The reference is computed once per
+evaluator and stays as the runner returned it. Where every output is a
+float32 ``jax.Array`` of its reference's shape, and the reference is
+one too, the tolerance check runs on the device
+(:func:`repro.engine.wallclock.outputs_close_on_device`) and only its
+int32 counts come back; the device is stricter than NumPy, never
+looser, so a non-zero count is handed to the host. Otherwise, and
+after such a count, both sides are copied to the host and
+:func:`~repro.engine.wallclock.assert_outputs_close` decides, raising
+with the candidate named.
+
 Spans (:mod:`repro.obs`): ``kernel.compile`` and ``kernel.timing``
 around the phases, each with the ``gate_s`` spent in it; one
 ``kernel.build`` per candidate around its build and first call; and
 the gate in three parts, ``kernel.reference`` (once per evaluator),
-``kernel.fetch`` (the output's copy to the host) and
-``kernel.compare`` (the tolerance check). No timed call is inside a
-span of its own.
+``kernel.compare`` (the tolerance check, with ``on`` set to
+``"device"`` or ``"host"``) and ``kernel.fetch`` (what comes to the
+host: the device check's counts, or the outputs for the host's
+check). No timed call is inside a span of its own.
 
 The store fingerprint keys on the measuring device (platform, device
 kind and count — :func:`repro.engine.wallclock.device_identity`) in
@@ -45,7 +58,6 @@ from __future__ import annotations
 
 import statistics
 import time
-from collections.abc import Mapping
 from typing import Sequence
 
 import numpy as np
@@ -53,8 +65,10 @@ import numpy as np
 from repro import obs
 from repro.core.costmodel import Machine
 from repro.engine.base import EvaluatorBase
-from repro.engine.wallclock import (_as_output_map, assert_outputs_close,
-                                    device_identity)
+from repro.engine.wallclock import (_as_output_map, _output_map,
+                                    assert_outputs_close,
+                                    comparable_on_device, device_identity,
+                                    outputs_close_on_device)
 from repro.space.params import ParamSpace
 
 
@@ -86,10 +100,11 @@ class KernelWallclockEvaluator(EvaluatorBase):
         self.repeats = max(1, repeats)
         self.warmup = max(1, warmup)
         self.check_values = check_values
-        self.rtol = rtol
-        # The gate's absolute tolerance is the kernel's own
-        # (KernelRunner.atol) unless the caller overrides it.
-        self.atol = runner.atol if atol is None else atol
+        # Python floats, so that NumPy's check is in the outputs' own
+        # precision, as the device's is. The absolute tolerance is the
+        # kernel's own (KernelRunner.atol) unless the caller overrides it.
+        self.rtol = float(rtol)
+        self.atol = float(runner.atol if atol is None else atol)
         self.compile_mode = compile_mode
         self.n_checked = 0
         self._reference: dict | None = None
@@ -106,20 +121,35 @@ class KernelWallclockEvaluator(EvaluatorBase):
     # -- reference outputs (computed lazily, once) -------------------------
     def _reference_outputs(self) -> dict:
         if self._reference is None:
+            import jax
+
             with obs.span("kernel.reference") as sp:
-                self._reference = _as_output_map(self.runner.reference())
+                self._reference = jax.block_until_ready(
+                    _output_map(self.runner.reference()))
                 sp.set(bytes=_nbytes(self._reference))
         return self._reference
 
     def _check(self, out, candidate) -> None:
         # The gate in three spans: the reference (its first use only),
-        # the output's copy to the host, and the tolerance check.
+        # the tolerance check, and what it brings to the host.
         ref = self._reference_outputs()
+        got = _output_map(out)
+        if comparable_on_device(got, ref):
+            with obs.span("kernel.compare", bytes=_nbytes(got),
+                          on="device"):
+                failing = outputs_close_on_device(
+                    got, ref, rtol=self.rtol, atol=self.atol
+                ).block_until_ready()
+            with obs.span("kernel.fetch", bytes=failing.nbytes):
+                passed = not np.asarray(failing).any()
+            if passed:
+                self.n_checked += 1
+                return
+        # The host's check: the only one that raises.
         with obs.span("kernel.fetch") as sp:
-            got = _as_output_map(out)
-            n = _nbytes(got)
-            sp.set(bytes=n)
-        with obs.span("kernel.compare", bytes=n):
+            got, ref = _as_output_map(got), _as_output_map(ref)
+            sp.set(bytes=_nbytes(got))
+        with obs.span("kernel.compare", bytes=_nbytes(got), on="host"):
             assert_outputs_close(
                 got, ref, rtol=self.rtol, atol=self.atol,
                 context=(f" for candidate "
@@ -201,8 +231,5 @@ class KernelWallclockEvaluator(EvaluatorBase):
 def _nbytes(out) -> int:
     """Bytes of a runner's outputs: an array, or a mapping or sequence
     of arrays."""
-    if isinstance(out, Mapping):
-        out = list(out.values())
-    if isinstance(out, (tuple, list)):
-        return sum(int(getattr(v, "nbytes", 0)) for v in out)
-    return int(getattr(out, "nbytes", 0))
+    return sum(int(getattr(v, "nbytes", 0))
+               for v in _output_map(out).values())

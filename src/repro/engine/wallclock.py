@@ -32,6 +32,7 @@ end-to-end wall-clock search anywhere.
 """
 from __future__ import annotations
 
+import functools
 import statistics
 import time
 from typing import Callable, Mapping, Sequence
@@ -64,14 +65,20 @@ def device_identity() -> str:
             f"kind={devices[0].device_kind}:count={len(devices)}")
 
 
-def _as_output_map(out) -> dict[str, np.ndarray]:
-    """Normalize a runner's outputs (mapping / sequence / single array)
-    to named numpy arrays for comparison."""
+def _output_map(out) -> dict:
+    """Name a runner's outputs (mapping / sequence / single array),
+    leaving each where it is."""
     if isinstance(out, Mapping):
-        return {str(k): np.asarray(v) for k, v in out.items()}
+        return {str(k): v for k, v in out.items()}
     if isinstance(out, (tuple, list)):
-        return {f"out{i}": np.asarray(v) for i, v in enumerate(out)}
-    return {"out": np.asarray(out)}
+        return {f"out{i}": v for i, v in enumerate(out)}
+    return {"out": out}
+
+
+def _as_output_map(out) -> dict[str, np.ndarray]:
+    """A runner's outputs as named numpy arrays, for comparison on the
+    host."""
+    return {k: np.asarray(v) for k, v in _output_map(out).items()}
 
 
 def assert_outputs_close(got, ref, *, rtol: float, atol: float = 0.0,
@@ -95,6 +102,84 @@ def assert_outputs_close(got, ref, *, rtol: float, atol: float = 0.0,
         np.testing.assert_allclose(
             got_map[k], r, rtol=rtol, atol=atol,
             err_msg=f"output {k!r} diverged{context}")
+
+
+def comparable_on_device(got, ref) -> bool:
+    """Whether :func:`outputs_close_on_device` may judge ``got`` against
+    ``ref``: every reference output is a float32 ``jax.Array``, and
+    ``got`` has a float32 ``jax.Array`` of the same shape under its
+    name. Anything else (a NumPy reference, a missing output, another
+    shape or dtype) is for :func:`assert_outputs_close` on the host.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    got, ref = _output_map(got), _output_map(ref)
+
+    def on_device(a) -> bool:
+        return isinstance(a, jax.Array) and a.dtype == jnp.float32
+
+    return all(k in got and on_device(r) and on_device(got[k])
+               and got[k].shape == r.shape for k, r in ref.items())
+
+
+def _failing(g, r, rtol, atol):
+    """The elements of ``g`` the device cannot pass against ``r``.
+
+    NumPy's ``isclose(g, r, rtol, atol, equal_nan=True)``, which is what
+    ``np.testing.assert_allclose`` tests, in float32: ``|g - r| <= atol
+    + rtol*|r|`` where ``r`` is finite, or ``g == r``, or both NaN. The
+    device may round the bound differently (a fused multiply-add) and
+    flushes subnormals to zero, so the check is stricter than NumPy's,
+    never looser: the bound is taken one ulp down and must be normal,
+    and a subnormal in either array fails here and is left to the host.
+    """
+    import jax.numpy as jnp
+    from jax import lax
+
+    def subnormal(x):
+        bits = lax.bitcast_convert_type(x, jnp.uint32)
+        return ((bits & 0x7F800000) == 0) & ((bits & 0x007FFFFF) != 0)
+
+    bound = (jnp.asarray(atol, r.dtype)
+             + jnp.asarray(rtol, r.dtype) * jnp.abs(r))
+    bound = jnp.nextafter(bound, jnp.asarray(-jnp.inf, r.dtype))
+    close = ((jnp.abs(g - r) <= bound)
+             & (bound >= jnp.finfo(r.dtype).tiny) & jnp.isfinite(r))
+    ok = close | (g == r) | (jnp.isnan(g) & jnp.isnan(r))
+    return ~ok | subnormal(g) | subnormal(r)
+
+
+def _count_failing(got: dict, ref: dict, rtol, atol):
+    import jax.numpy as jnp
+
+    return jnp.stack([jnp.sum(_failing(got[k], r, rtol, atol),
+                              dtype=jnp.int32) for k, r in ref.items()])
+
+
+@functools.cache
+def _count_failing_jit():
+    import jax
+
+    return jax.jit(_count_failing, static_argnames=("rtol", "atol"))
+
+
+def outputs_close_on_device(got, ref, *, rtol: float, atol: float = 0.0):
+    """The value gate's tolerance check, run where the outputs are.
+
+    ``got`` and ``ref`` must pass :func:`comparable_on_device`. Returns
+    an int32 ``jax.Array`` with the number of elements of each reference
+    output, in the order of their sorted names, that the device cannot
+    pass (:func:`_failing`): all zeros only where
+    :func:`assert_outputs_close` passes too. One jitted reduction over
+    the outputs, compiled once per set of shapes and tolerances (a
+    space has one pair); the tolerances are constants of it, so a call
+    copies nothing to the device. A non-zero count is not a verdict:
+    the host decides it with :func:`assert_outputs_close`.
+    """
+    got, ref = _output_map(got), _output_map(ref)
+    return _count_failing_jit()({k: got[k] for k in ref}, ref,
+                                rtol=float(rtol), atol=float(atol))
 
 
 class ExecutorEvaluator(EvaluatorBase):

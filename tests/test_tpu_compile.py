@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import spmv_dag
 from repro.core.executor import build_runner
+from repro.engine import wallclock
 from repro.engine.wallclock import demo_spmv_impls, reference_schedule
 from repro.kernels.flash_attention.ops import mha
 from repro.kernels.pack.kernel import pack
@@ -94,3 +95,15 @@ def test_token_chain_runner_compiles(shape):
     env_shapes = {k: shape(v.shape, v.dtype) for k, v in env.items()}
     compiled = jax.jit(run).lower(env_shapes).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_value_gate_check_compiles_to_one_reduction(shape):
+    """The gate's device check at the attention cell's output (1 x 16 x
+    4096 x 128 float32): one fused reduction, no full-size temporary."""
+    out = {"out": shape((1, 16, 4096, 128))}
+    compiled = wallclock._count_failing_jit().lower(
+        out, out, rtol=1e-4, atol=2e-2).compile()
+    fusions = [line for line in compiled.as_text().splitlines()
+               if " fusion(" in line]
+    assert len(fusions) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
