@@ -1,5 +1,13 @@
 """moonshot-v1-16b-a3b [moe]: kimi/moonlight, 64 routed top-6 + 2 shared
-[hf:moonshotai/Moonlight-16B-A3B]."""
+[hf:moonshotai/Moonlight-16B-A3B].
+
+An approximation, not the published model: Moonlight's attention is
+multi-head latent attention (MLA: ``kv_lora_rank`` 512, ``qk_nope_head_dim``
+128, ``qk_rope_head_dim`` 64, ``v_head_dim`` 128, 27 layers), which the
+model zoo does not implement; this config stands in plain MHA with 16
+kv heads. The latent attention itself, at decode over a shared 576-wide
+latent cache, is ``repro.kernels.mla_decode`` (kernel, plain references
+of the absorbed and the published layer, and its tuning space)."""
 from repro.models.config import ModelConfig, MoeConfig
 
 CONFIG = ModelConfig(

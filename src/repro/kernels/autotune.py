@@ -11,11 +11,13 @@ per-platform block-size design rules by :func:`repro.rules.distill`.
 
 Each factory closes the kernel over one fixed, seeded problem instance,
 drawn on the host (a ``space.instance`` span) and then copied to the
-device (``space.put``). The instance is part of the space — its
-shape/seed go into the ``signature`` hashed by the store fingerprint,
-so measurements from different instances never alias. Shapes default
-small enough that the interpret-mode (CPU) sweep stays in test
-budgets; pass bigger ones for a real tuning run on TPU.
+device (``space.put``); ``mla_decode``'s gigabyte-sized instance is
+drawn on the device instead (``space.instance`` with ``on="device"``),
+where a host draw would take seconds. The instance is part of the
+space — its shape/seed go into the ``signature`` hashed by the store
+fingerprint, so measurements from different instances never alias.
+Shapes default small enough that the interpret-mode (CPU) sweep stays
+in test budgets; pass bigger ones for a real tuning run on TPU.
 ``interpret=None`` (the default) compiles the kernels everywhere but
 on the CPU backend (:func:`repro.kernels.resolve_interpret`).
 
@@ -30,7 +32,8 @@ import numpy as np
 from repro import obs
 from repro.space.params import KernelRunner, ParamSpace
 
-__all__ = ["flash_attention_space", "spmv_mulsum_space", "pack_space"]
+__all__ = ["flash_attention_space", "spmv_mulsum_space", "pack_space",
+           "mla_decode_space", "mla_decode_instance", "prng_key"]
 
 
 def _put(*host: np.ndarray) -> tuple:
@@ -48,7 +51,7 @@ def _divisors_of(seq: int, values) -> tuple[int, ...]:
     if not out:
         raise ValueError(
             f"no candidate block size in {tuple(values)} divides "
-            f"sequence length {seq}")
+            f"{seq}")
     return out
 
 
@@ -183,3 +186,117 @@ def pack_space(*, n: int = 4096, m: int = 512,
             build=build,
             reference=lambda: pack_ref(x, idx)),
         signature=f"pack:n={n}:m={m}:dtype=float32:seed={seed}")
+
+
+def prng_key(seed):
+    """A ``jax.random`` key from a seed or a sequence of seeds, each a
+    whole number in [0, 2**64): both 32-bit halves of each are folded in
+    (``jax.random.key`` alone would drop the high half)."""
+    import jax
+
+    key = jax.random.key(0)
+    for s in (seed if isinstance(seed, (list, tuple)) else [seed]):
+        s = int(s)
+        if not 0 <= s < 1 << 64:
+            raise ValueError(f"seed {s} is not in [0, 2**64)")
+        key = jax.random.fold_in(key, s & 0xFFFFFFFF)
+        key = jax.random.fold_in(key, s >> 32)
+    return key
+
+
+def mla_decode_instance(batch: int, heads: int, width: int, s_max: int,
+                        seed, layers: int = 1) -> tuple[list, list]:
+    """Per layer, queries q (batch, heads, width) and a feature-major
+    latent cache (batch, width, s_max): bfloat16 standard normals drawn
+    on the default device from ``seed``, layer l's q from key 2l and its
+    cache from key 2l + 1 folded into :func:`prng_key`."""
+    import jax
+    import jax.numpy as jnp
+
+    key = prng_key(seed)
+    qs, caches = [], []
+    for layer in range(layers):
+        qs.append(jax.random.normal(jax.random.fold_in(key, 2 * layer),
+                                    (batch, heads, width), jnp.bfloat16))
+        caches.append(jax.random.normal(
+            jax.random.fold_in(key, 2 * layer + 1), (batch, width, s_max),
+            jnp.bfloat16))
+    return qs, caches
+
+
+def mla_decode_space(*, layers: int = 1, batch: int = 4, heads: int = 16,
+                     kv_lora_rank: int = 512, qk_rope_head_dim: int = 64,
+                     qk_nope_head_dim: int = 128, s_max: int = 256,
+                     min_len: int = 16, max_len: int = 256,
+                     order_seed: int = 0,
+                     block_k_values=(128, 256, 512, 1024, 2048),
+                     block_b_values=(1, 2, 4, 8), seed=0,
+                     interpret: bool | None = None) -> ParamSpace:
+    """(block_k, block_b) grid for absorbed MLA decode attention
+    (:func:`repro.kernels.mla_decode.ops.mla_decode`) over one ragged
+    decode batch, bfloat16 as deployed: a candidate's run is one decode
+    step's attention over ``layers`` layers
+    (:func:`~repro.kernels.mla_decode.ops.mla_decode_layers`), one
+    kernel call a layer in one dispatch.
+
+    The instance is drawn on the device from ``seed``
+    (:func:`mla_decode_instance`, D = kv_lora_rank + qk_rope_head_dim);
+    every token past a sequence's length holds a draw too, as a reused
+    cache holds stale entries. The lengths are
+    :func:`~repro.kernels.mla_decode.ops.log_uniform_lengths` over
+    [min_len, max_len], fixed by ``order_seed`` and not by ``seed``.
+    Block values are filtered to divisors of ``s_max`` and ``batch``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.mla_decode.ops import (log_uniform_lengths,
+                                              mla_decode_layers,
+                                              mla_decode_ref, softmax_scale)
+
+    if max_len > s_max:
+        raise ValueError(f"max_len {max_len} exceeds the cache's {s_max}")
+    block_k = _divisors_of(s_max, block_k_values)
+    block_b = _divisors_of(batch, block_b_values)
+    width = kv_lora_rank + qk_rope_head_dim
+    scale = softmax_scale(qk_nope_head_dim, qk_rope_head_dim)
+    with obs.span("space.instance", on="device") as sp:
+        qs, caches = mla_decode_instance(batch, heads, width, s_max, seed,
+                                         layers)
+        lengths = jnp.asarray(log_uniform_lengths(batch, min_len, max_len,
+                                                  order_seed))
+        jax.block_until_ready((qs, caches, lengths))
+        sp.set(bytes=sum(a.nbytes for a in qs + caches) + lengths.nbytes)
+
+    def build(params: dict):
+        bk, bb = params["block_k"], params["block_b"]
+
+        def run():
+            return mla_decode_layers(qs, caches, lengths, block_k=bk,
+                                     block_b=bb, scale=scale,
+                                     value_dim=kv_lora_rank,
+                                     interpret=interpret)
+        return run
+
+    return ParamSpace(
+        "mla_decode",
+        [("block_k", block_k), ("block_b", block_b)],
+        # The kernel rounds its probabilities to bfloat16 for the value
+        # dot, so its error grows as sequences shorten: largest |kernel
+        # - reference| over the 20 candidates on a TPU v5e at 128 x
+        # 8,192 (lengths 1,024-8,192) was 7.93e-4, and 2.5e-3 on the
+        # CPU at lengths 16-256. The gate allows 5e-3; the float8
+        # control misses by 0.11 at the chip's size, and the planted
+        # masking, value-feature and scale faults by more than 10x
+        # (tests/test_mla_decode.py; PERF.md).
+        runner=KernelRunner(
+            build=build,
+            reference=lambda: jnp.stack([
+                mla_decode_ref(q, c, lengths, scale, value_dim=kv_lora_rank)
+                for q, c in zip(qs, caches)]),
+            atol=5e-3),
+        signature=(f"mla_decode:layers={layers}:b={batch}:h={heads}:"
+                   f"d={width}:dv={kv_lora_rank}:s_max={s_max}:"
+                   f"lengths=loguniform("
+                   f"{min_len},{max_len},order={order_seed}):"
+                   f"scale={scale!r}:dtype=bfloat16:seed={seed}"))

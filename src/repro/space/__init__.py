@@ -9,8 +9,9 @@ tunable knobs like kernel block sizes, and a name registry
 select spaces by name.
 
 The kernel parameter spaces (``flash_attention``, ``spmv_mulsum``,
-``pack`` — :mod:`repro.kernels.autotune`) are registered through lazy
-factories: importing this package never imports JAX.
+``pack``, ``mla_decode`` — :mod:`repro.kernels.autotune`) are
+registered through lazy factories: importing this package never
+imports JAX.
 """
 from repro.space.base import (SPACES, DesignSpace, as_space, make_space,
                               register_space)
@@ -63,5 +64,6 @@ register_space("halo3d", _schedule_factory(_halo3d))
 register_space("flash_attention", _kernel_factory("flash_attention_space"))
 register_space("spmv_mulsum", _kernel_factory("spmv_mulsum_space"))
 register_space("pack", _kernel_factory("pack_space"))
+register_space("mla_decode", _kernel_factory("mla_decode_space"))
 # Analytic demo grid (tests, smoke runs; no JAX).
 register_space("demo", demo_param_space)

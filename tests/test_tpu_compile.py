@@ -8,7 +8,9 @@ called with ``interpret=False``. The topology is described inside a
 fixture, never at import: only the worker that runs this file loads
 the TPU library.
 """
+import json
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -20,11 +22,15 @@ from repro.core.executor import build_runner
 from repro.engine import wallclock
 from repro.engine.wallclock import demo_spmv_impls, reference_schedule
 from repro.kernels.flash_attention.ops import mha
+from repro.kernels.mla_decode.kernel import mla_decode
 from repro.kernels.pack.kernel import pack
 from repro.kernels.spmv.kernel import ell_mulsum
 from repro.kernels.spmv.ops import ell_matvec_onehot
 
 PAPER_N, PAPER_K = 150_000, 10          # the paper's matrix (spmv/matrix.py)
+# The MLA decode cell's configuration: its size and its block grid.
+MLA = json.loads((pathlib.Path(__file__).resolve().parents[1] / "bench"
+                  / "configs" / "moonlight_mla_decode.json").read_text())
 
 
 @pytest.fixture(scope="module")
@@ -80,6 +86,36 @@ def test_flash_attention_compiles_at_smollm_width(shape):
     qkv = shape((1, 15, 2048, 64))        # smollm-360m: 15 heads x 64
     _kernel_compiled(mha.lower(qkv, qkv, qkv, causal=True, block_q=128,
                                block_k=128, interpret=False))
+
+
+def _mla_shapes(shape):
+    b, h = MLA["batch"], MLA["num_attention_heads"]
+    d = MLA["kv_lora_rank"] + MLA["qk_rope_head_dim"]
+    return (shape((b, h, d), jnp.bfloat16),
+            shape((b, d, MLA["s_max"]), jnp.bfloat16),
+            shape((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("block_b", MLA["block_b"])
+@pytest.mark.parametrize("block_k", MLA["block_k"])
+def test_mla_decode_compiles_at_the_cells_size(shape, block_k, block_b):
+    """Every candidate of the Moonlight decode cell, at 128 sequences x
+    8,192 positions x 576 features, with no copy of the cache beside
+    the kernel (its feature-major layout is the chip's default)."""
+    lowered = mla_decode.lower(*_mla_shapes(shape), block_k=block_k,
+                               block_b=block_b, scale=192 ** -0.5,
+                               value_dim=MLA["kv_lora_rank"],
+                               interpret=False)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mla_decode_refuses_unaligned_lane_block(shape):
+    q, cache, lengths = _mla_shapes(shape)
+    with pytest.raises(Exception, match="128"):
+        mla_decode.lower(q, cache, lengths, block_k=64, block_b=1,
+                         scale=192 ** -0.5, interpret=False).compile()
 
 
 def test_pack_compiles(shape):
