@@ -67,6 +67,7 @@ def flash_attention_space(*, batch: int = 1, heads: int = 2,
     unpadded paths measure the same problem (and causal right-aligned
     masking needs equal q/kv padding anyway).
     """
+    from repro.kernels.flash_attention.kernel import block_table
     from repro.kernels.flash_attention.ops import mha
     from repro.kernels.flash_attention.ref import attention_ref
 
@@ -81,6 +82,13 @@ def flash_attention_space(*, batch: int = 1, heads: int = 2,
 
     def build(params: dict):
         bq, bk = params["block_q"], params["block_k"]
+        # A call's grid steps, against the full (q block, kv block)
+        # rectangle's: the blocks divide ``seq``, so nothing is padded.
+        n_q, n_kv = seq // bq, seq // bk
+        steps = block_table(n_q, n_kv, block_q=bq, block_k=bk, q_offset=0,
+                            causal=causal).qi.size
+        obs.counter("flash.grid_steps").add(batch * heads * steps)
+        obs.counter("flash.grid_steps_dense").add(batch * heads * n_q * n_kv)
 
         def run():
             return mha(q, k, v, causal=causal, block_q=bq,

@@ -375,9 +375,11 @@ def _mask_off_by_one(body):
 
 
 def _state_not_carried(body):
-    def faulty(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, **kw):
+    def faulty(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, o_ref,
+               m_scr, l_scr, acc_scr, **kw):
         m_scr[...] = jnp.full_like(m_scr, fa_kernel.NEG_INF)
-        body(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, **kw)
+        body(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, o_ref,
+             m_scr, l_scr, acc_scr, **kw)
     return faulty
 
 
@@ -410,6 +412,26 @@ def test_flash_gate_rejects_planted_faults(planted_flash_fault, fault):
     ev = E.make_evaluator(sp, "wallclock", repeats=1)
     with pytest.raises(AssertionError, match="value-correctness gate"):
         ev.evaluate([(16, 16)])
+
+
+@pytest.mark.parametrize("causal,steps", [(True, 6), (False, 8)])
+def test_flash_space_build_counts_grid_steps(causal, steps):
+    """Building a candidate adds its call's grid steps, read from the
+    kernel's block table, and the full rectangle's: at 64 positions in
+    16 x 32 blocks the causal table keeps 6 of 8 (q block, kv block)
+    pairs a head."""
+    from repro.kernels.flash_attention.kernel import block_table
+
+    sp = flash_attention_space(batch=1, heads=2, seq=64, head_dim=16,
+                               block_values=(16, 32), causal=causal,
+                               interpret=True)
+    table = block_table(4, 2, block_q=16, block_k=32, q_offset=0,
+                        causal=causal)
+    assert table.qi.size == steps
+    with obs.use(obs.Telemetry()) as tel:
+        sp.runner.build({"block_q": 16, "block_k": 32})
+    assert tel.counters() == {"flash.grid_steps": 2 * steps,
+                              "flash.grid_steps_dense": 2 * 8}
 
 
 def test_pack_space_smallest_grid_round_trip():

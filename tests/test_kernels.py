@@ -151,3 +151,114 @@ def test_flash_attention_decode_alignment():
     ref = fa_ops.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16)])
+def test_flash_attention_causal_unequal_blocks(block_q, block_k):
+    """Causal self-attention whose q and kv blocks differ: the block
+    table's staircase steps by more than one kv block per q block, or
+    stays on one kv block for several q blocks."""
+    rng = np.random.default_rng(block_q + 2 * block_k)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, 128, 64)),
+                           jnp.float32) for _ in range(3))
+    out = fa_ops.mha(q, k, v, causal=True, block_q=block_q,
+                     block_k=block_k)
+    ref = fa_ops.attention_ref(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16)])
+def test_flash_attention_decode_alignment_unequal_blocks(block_q,
+                                                         block_k):
+    """Right-aligned causal at unequal blocks: the table's staircase is
+    shifted by the 256 keys before the first query."""
+    rng = np.random.default_rng(3 * block_q + block_k)
+    q = jnp.asarray(rng.standard_normal((1, 1, 128, 64)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 1, 384, 64)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 1, 384, 64)), jnp.float32)
+    out = fa_ops.mha(q, k, v, causal=True, block_q=block_q,
+                     block_k=block_k)
+    ref = fa_ops.attention_ref(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5)
+
+
+# -- the flash kernel's block table ---------------------------------------------
+
+from repro.kernels.flash_attention import kernel as fa_kernel  # noqa: E402
+
+
+def _padded_table(sq, skv, block_q, block_k, causal):
+    """The table ``mha`` hands the kernel: lengths padded to the blocks
+    at the end, queries right-aligned on the keys."""
+    sq_p, skv_p = sq + (-sq) % block_q, skv + (-skv) % block_k
+    q_offset = skv_p - sq_p
+    table = fa_kernel.block_table(sq_p // block_q, skv_p // block_k,
+                                  block_q=block_q, block_k=block_k,
+                                  q_offset=q_offset, causal=causal)
+    return table, sq_p // block_q, skv_p // block_k, q_offset
+
+
+@pytest.mark.parametrize("sq,skv,block_q,block_k,causal", [
+    (256, 256, 32, 32, True),
+    (256, 256, 16, 64, True),        # block_q < block_k
+    (256, 256, 64, 16, True),        # block_q > block_k
+    (128, 384, 16, 32, True),        # right-aligned, q_offset 256
+    (128, 384, 32, 16, True),
+    (96, 200, 32, 8, True),          # q_offset 104, not a block multiple
+    (300, 300, 64, 64, True),        # both padded to 320
+    (100, 100, 16, 16, True),        # both padded to 112
+    (128, 256, 32, 64, False),
+    (256, 128, 64, 32, False),
+])
+def test_flash_block_table_matches_brute_force(sq, skv, block_q, block_k,
+                                               causal):
+    """Every (query, key) pair of every block, checked one by one: the
+    table lists exactly the blocks with a visible pair, by q block and
+    then kv block; each q block finishes on its last listed kv block,
+    which is ``last(qi)``; and a block straddles exactly where it holds
+    visible and masked pairs both."""
+    table, n_q, n_kv, q_offset = _padded_table(sq, skv, block_q, block_k,
+                                               causal)
+    want = []
+    for qi in range(n_q):
+        q_pos = qi * block_q + np.arange(block_q)[:, None] + q_offset
+        for ki in range(n_kv):
+            k_pos = ki * block_k + np.arange(block_k)[None, :]
+            visible = (k_pos <= q_pos) if causal else np.ones(
+                (block_q, block_k), bool)
+            if visible.any():
+                want.append((qi, ki, not visible.all()))
+    got = list(zip(table.qi.tolist(), table.ki.tolist(),
+                   ((table.flags & fa_kernel.STRADDLES) != 0).tolist()))
+    assert got == want
+    finishes = (table.flags & fa_kernel.FINISHES) != 0
+    for qi in range(n_q):
+        last = n_kv - 1
+        if causal:
+            last = min(n_kv - 1,
+                       (qi * block_q + block_q - 1 + q_offset) // block_k)
+        mine = np.flatnonzero(table.qi == qi)
+        assert table.ki[mine[-1]] == last
+        assert finishes[mine].tolist() == [False] * (mine.size - 1) + [True]
+    assert table.qi.dtype == table.ki.dtype == table.flags.dtype == np.int32
+
+
+@pytest.mark.parametrize("block_q,block_k,steps,straddling", [
+    (32, 32, 8256, 128),
+    (32, 64, 4160, 128), (64, 32, 4160, 128),
+    (32, 128, 2112, 128), (128, 32, 2112, 128),
+    (64, 64, 2080, 64),
+    (64, 128, 1056, 64), (128, 64, 1056, 64),
+    (128, 128, 528, 32),
+])
+def test_flash_block_table_at_the_attention_cells_shape(block_q, block_k,
+                                                        steps, straddling):
+    """Steps per head of each candidate of the 4,096-position causal
+    attention cell, against the rectangle's (32 x 32 blocks: 16,384)."""
+    table = fa_kernel.block_table(4096 // block_q, 4096 // block_k,
+                                  block_q=block_q, block_k=block_k,
+                                  q_offset=0, causal=True)
+    assert table.qi.size == steps
+    assert np.count_nonzero(table.flags & fa_kernel.STRADDLES) == straddling

@@ -31,6 +31,9 @@ PAPER_N, PAPER_K = 150_000, 10          # the paper's matrix (spmv/matrix.py)
 # The MLA decode cell's configuration: its size and its block grid.
 MLA = json.loads((pathlib.Path(__file__).resolve().parents[1] / "bench"
                   / "configs" / "moonlight_mla_decode.json").read_text())
+# The causal attention cell's configuration: its shape and its blocks.
+ATTN = json.loads((pathlib.Path(__file__).resolve().parents[1] / "bench"
+                   / "configs" / "dsmoe16b_attn.json").read_text())
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +89,18 @@ def test_flash_attention_compiles_at_smollm_width(shape):
     qkv = shape((1, 15, 2048, 64))        # smollm-360m: 15 heads x 64
     _kernel_compiled(mha.lower(qkv, qkv, qkv, causal=True, block_q=128,
                                block_k=128, interpret=False))
+
+
+@pytest.mark.parametrize("block", [min(ATTN["blocks"]),
+                                   max(ATTN["blocks"])])
+def test_flash_attention_compiles_at_the_cells_size(shape, block):
+    """The attention cell's 16 heads x 4,096 positions x 128 at its
+    smallest block, whose table (8,256 steps) takes the most SMEM, and
+    at its largest."""
+    h = ATTN["num_attention_heads"]
+    qkv = shape((ATTN["batch"], h, ATTN["seq"], ATTN["hidden_size"] // h))
+    _kernel_compiled(mha.lower(qkv, qkv, qkv, causal=True, block_q=block,
+                               block_k=block, interpret=False))
 
 
 def _mla_shapes(shape):
